@@ -27,8 +27,8 @@ from pathlib import Path
 import os
 
 from repro.core import (DiurnalArrivals, PoissonArrivals, ServeLoop,
-                        TenantSpec, build_orchestrators, build_testbed,
-                        ground_truth_traverser, heye_traverser,
+                        TenantSpec, Testbed, build_orchestrators,
+                        build_testbed, ground_truth_traverser, heye_traverser,
                         single_task_request)
 from repro.serve.admission import AdaptiveWindow, AdmissionController
 
@@ -49,7 +49,9 @@ _HORIZON = 10.0
 _X64_WALL_RPS_FLOOR = 200.0
 
 
-def _serve_once(mult: int, batch_window=0.0):
+def _serve_loop(mult: int, batch_window=0.0) -> tuple[ServeLoop, Testbed]:
+    """The mult-scaled mining fleet's ServeLoop (not yet run) and its
+    testbed."""
     ec, sc = mining_counts(mult)
     tb = build_testbed(edge_counts=ec, server_counts=sc)
     root = build_orchestrators(tb.graph, heye_traverser(tb.graph))
@@ -73,6 +75,11 @@ def _serve_once(mult: int, batch_window=0.0):
                                                    max_defers=1),
                      batch_window=batch_window,
                      horizon=horizon)
+    return loop, tb
+
+
+def _serve_once(mult: int, batch_window=0.0):
+    loop, tb = _serve_loop(mult, batch_window)
     stats = loop.run()
     if stats.engine_opens != 1:
         raise AssertionError(

@@ -39,15 +39,17 @@ pools at once over the same arrays — ``factor_batch`` (joint factors of a
 co-running pool, used by the Traverser at contention-interval boundaries),
 ``slowdown_matrix`` (all pairwise co-run factors in one shot) and
 ``factors_with_candidates`` (the Orchestrator's one-shot constraint check
-over every candidate PU).  The factor-aggregation inner loop dispatches to
-a Pallas kernel on TPU (kernels/slowdown_kernel.py) and to the equivalent
-numpy reference elsewhere.  The numpy path matches the scalar path to
-1e-9; the TPU kernel computes in fp32 (~1e-6 relative) — set
-``REPRO_SLOWDOWN_KERNEL=ref`` to force strict float64 parity on any
-backend, or ``=pallas`` to force the kernel.
+over every candidate PU).  The factor-aggregation inner loop runs the
+Pallas kernel (kernels/slowdown_kernel.py) when ``jax.default_backend()``
+is ``tpu`` and the equivalent float64 numpy reference on every other
+backend.  The numpy path matches the scalar path to 1e-9; the TPU kernel
+computes in fp32 (within 1e-6 relative of the reference).
+``REPRO_SLOWDOWN_KERNEL=ref`` forces the numpy path on any backend,
+``=pallas`` forces the kernel (interpret mode off-TPU).
 """
 from __future__ import annotations
 
+import os
 import threading
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
@@ -129,7 +131,7 @@ def _aggregate_np(x: np.ndarray, beta: np.ndarray, mem: np.ndarray,
     """factors[i] = (1+mt_term[i]) * prod_r(1 + pterm(beta[r], x[i,r])*mem[i]).
 
     Same formula as ``kernels.ref.slowdown_factors_ref`` (the Pallas
-    oracle); kept inline so pure-DES workflows never import jax."""
+    oracle); kept inline so the CPU path never imports the kernels."""
     term = _pterm_arr(beta[None, :], x, kappa)
     return np.maximum(1.0, (1.0 + mt_term)
                       * np.prod(1.0 + term * mem[:, None], axis=-1))
@@ -139,15 +141,8 @@ _AGGREGATE = None
 
 
 def _aggregate(x, beta, mem, mt_term, kappa):
-    """Batched factor-aggregation inner loop.
-
-    Selected once: the Pallas kernel when jax is loaded and reports a TPU
-    backend (the same ``on_tpu`` switch the other kernels use), else the
-    numpy reference.  jax is never imported just to make this choice, so
-    CPU-only DES runs stay jax-free.  ``REPRO_SLOWDOWN_KERNEL`` overrides
-    the choice (``ref`` | ``pallas`` | ``auto``): the kernel runs in fp32,
-    so deployments that need bit-stable scheduling across backends pin
-    ``ref``."""
+    """Batched factor-aggregation inner loop, through the implementation
+    :func:`_select_aggregate` picks once per process."""
     global _AGGREGATE
     if _AGGREGATE is None:
         _AGGREGATE = _select_aggregate()
@@ -155,30 +150,19 @@ def _aggregate(x, beta, mem, mt_term, kappa):
 
 
 def _select_aggregate():
-    import os
-    import sys
+    """The Pallas kernel on a TPU backend, the numpy reference elsewhere.
+
+    ``REPRO_SLOWDOWN_KERNEL`` overrides the choice (``ref`` | ``pallas`` |
+    ``auto``).  The kernel runs in fp32, so deployments that need
+    bit-stable scheduling across backends pin ``ref``.  A kernel that
+    fails raises; nothing falls back to the host in silence."""
     mode = os.environ.get("REPRO_SLOWDOWN_KERNEL", "auto").lower()
     if mode == "ref":
         return _aggregate_np
-    if mode == "pallas":
+    import jax
+    if mode == "pallas" or jax.default_backend() == "tpu":
         from ..kernels.slowdown_kernel import slowdown_factors_pallas
-
-        def _pallas_forced(x, beta, mem, mt_term, kappa):
-            return np.asarray(slowdown_factors_pallas(x, beta, mem, mt_term,
-                                                      kappa))
-        return _pallas_forced
-    if "jax" in sys.modules:
-        try:
-            import jax
-            if jax.default_backend() == "tpu":
-                from ..kernels.slowdown_kernel import slowdown_factors
-
-                def _pallas(x, beta, mem, mt_term, kappa):
-                    return np.asarray(slowdown_factors(x, beta, mem, mt_term,
-                                                       kappa))
-                return _pallas
-        except Exception:       # pragma: no cover - jax probe best-effort
-            pass
+        return slowdown_factors_pallas
     return _aggregate_np
 
 

@@ -1,18 +1,19 @@
 """Pallas kernels + pure-jnp references.
 
-Compat shim: JAX renamed ``pltpu.TPUCompilerParams`` to
-``pltpu.CompilerParams`` across 0.4.x releases.  The kernels in this
-package use the new spelling; on installs that only ship the old one
-(e.g. 0.4.37) we alias it here so both spellings work.  This runs before
-any kernel module is imported (importing a submodule triggers this
-package ``__init__`` first), so every ``pltpu.CompilerParams(...)`` call
-site resolves regardless of the installed JAX.
+Every device path imports this package first, so the persistent compile
+cache is placed here.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left
+to JAX; otherwise the cache lives at ``.jax_cache/`` in the root of the
+checkout.  The minimum compile time is 0 so the sub-second kernel
+compiles are kept too.
 """
-from jax.experimental.pallas import tpu as _pltpu
+import os
+from pathlib import Path
 
-if not hasattr(_pltpu, "CompilerParams"):        # old JAX, new spelling used
-    _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-if not hasattr(_pltpu, "TPUCompilerParams"):     # new JAX, old spelling used
-    _pltpu.TPUCompilerParams = _pltpu.CompilerParams
+import jax
 
-from . import ops, ref, slowdown_kernel, timeline_kernel
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_compilation_cache_dir",
+                      str(Path(__file__).resolve().parents[3] / ".jax_cache"))
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+from . import ops, ref, slowdown_kernel, timeline_kernel  # noqa: E402

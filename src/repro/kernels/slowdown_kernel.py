@@ -7,15 +7,17 @@ pure map over pool members:
     factor[i] = max(1, (1 + mt_term[i])
                        * prod_r(1 + beta[r]*x[i,r]*(1+kappa*x[i,r]) * mem[i]))
 
-On a TPU backend this lowers natively (rows tile the sublanes, the tiny
-rclass axis pads the lanes).  Everywhere else ``slowdown_factors``
-selects the numpy reference (``ref.slowdown_factors_ref``) — the same
-``on_tpu`` switch the other kernels use, except that here the CPU
-fallback is the oracle itself rather than interpret mode: this runs per
-contention interval inside the DES hot loop, where interpret-mode
-execution would defeat the point of the batching.  Interpret mode stays
-available through ``slowdown_factors_pallas(interpret=True)`` for the
-parity tests.
+The kernel works on the transposed pool: the rclass axis (at most
+``len(RCLASSES)`` = 8 rows) fills one sublane tile and the pool members
+run along the lanes, so the output is one lane-dense ``(1, N)`` row.  The
+product over rclasses is unrolled over the real rclass rows (Mosaic has
+no ``reduce_prod`` lowering).  Pools are padded to a power-of-two width
+(:func:`bucket`), so a run compiles one kernel per power of two its pools
+reach; padded members are dropped after the call.
+
+``core.slowdown`` selects this kernel on a TPU backend and the float64
+numpy reference (``ref.slowdown_factors_ref``) everywhere else.  Off-TPU
+the kernel runs in interpret mode, which the parity tests use.
 """
 from __future__ import annotations
 
@@ -28,67 +30,75 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import ref
+_SUBLANES = 8
+_MIN_BUCKET = 8
+_MAX_BLOCK = 2048          # lanes per grid step; larger buckets tile
 
-_LANES = 128
+
+def bucket(n: int) -> int:
+    """Padded pool width for an ``n``-member pool: the next power of two,
+    at least 8."""
+    return max(_MIN_BUCKET, 1 << (max(n, 1) - 1).bit_length())
 
 
-def _factors_kernel(x_ref, beta_ref, mem_ref, mt_ref, o_ref, *, kappa):
-    x = x_ref[...].astype(jnp.float32)           # (bn, R)
-    beta = beta_ref[...].astype(jnp.float32)     # (1, R)
-    mem = mem_ref[...].astype(jnp.float32)       # (bn, 1)
-    mt = mt_ref[...].astype(jnp.float32)         # (bn, 1)
-    term = jnp.where((x > 0.0) & (beta > 0.0),
-                     beta * x * (1.0 + kappa * x), 0.0)
-    f = (1.0 + mt) * jnp.prod(1.0 + term * mem, axis=-1, keepdims=True)
+def _factors_kernel(x_ref, beta_ref, mem_ref, mt_ref, o_ref, *, kappa, n_r):
+    mem = mem_ref[...]                           # (1, bn)
+    prod = None
+    for r in range(n_r):                         # static unroll
+        x = x_ref[r:r + 1, :]                    # (1, bn)
+        beta = beta_ref[r:r + 1, :]              # (1, 1)
+        term = jnp.where((x > 0.0) & (beta > 0.0),
+                         beta * x * (1.0 + kappa * x), 0.0)
+        g = 1.0 + term * mem
+        prod = g if prod is None else prod * g
+    f = 1.0 + mt_ref[...]
+    if prod is not None:
+        f = f * prod
     o_ref[...] = jnp.maximum(f, 1.0)
 
 
-def slowdown_factors_pallas(x: jax.Array, beta: jax.Array, mem: jax.Array,
-                            mt_term: jax.Array, kappa: float, *,
-                            block_n: int = 256,
-                            interpret: Optional[bool] = None) -> jax.Array:
-    """(N, R) pressures -> (N,) factors via pl.pallas_call."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x = jnp.asarray(x, jnp.float32)
-    N, R = x.shape
-    pad_r = (-R) % _LANES
-    bn = min(block_n, max(N, 1))
-    pad_n = (-N) % bn
-    # zero rclass padding contributes a factor-term of exactly 1.0; padded
-    # rows are dropped after the call
-    xp = jnp.pad(x, ((0, pad_n), (0, pad_r)))
-    betap = jnp.pad(jnp.asarray(beta, jnp.float32), (0, pad_r))[None, :]
-    memp = jnp.pad(jnp.asarray(mem, jnp.float32), (0, pad_n))[:, None]
-    mtp = jnp.pad(jnp.asarray(mt_term, jnp.float32), (0, pad_n))[:, None]
-    Np, Rp = N + pad_n, R + pad_r
-    out = pl.pallas_call(
-        functools.partial(_factors_kernel, kappa=kappa),
-        grid=(Np // bn,),
-        in_specs=[
-            pl.BlockSpec((bn, Rp), lambda i: (i, 0)),
-            pl.BlockSpec((1, Rp), lambda i: (0, 0)),
-            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((bn, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((Np, 1), jnp.float32),
+@functools.partial(jax.jit, static_argnames=("kappa", "n_r", "interpret"))
+def factors_call(xt, beta, mem, mt, *, kappa: float, n_r: int,
+                 interpret: bool):
+    """The padded kernel call: ``xt`` (Rp, Np) pressures with rclasses on
+    the sublanes, ``beta`` (Rp, 1), ``mem``/``mt`` (1, Np) -> (1, Np)."""
+    rp, npad = xt.shape
+    bn = min(npad, _MAX_BLOCK)
+    row = pl.BlockSpec((1, bn), lambda i: (0, i))
+    return pl.pallas_call(
+        functools.partial(_factors_kernel, kappa=kappa, n_r=n_r),
+        grid=(npad // bn,),
+        in_specs=[pl.BlockSpec((rp, bn), lambda i: (0, i)),
+                  pl.BlockSpec((rp, 1), lambda i: (0, 0)),
+                  row, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(xp, betap, memp, mtp)
-    return out[:N, 0]
+    )(xt, beta, mem, mt)
 
 
-def slowdown_factors(x, beta, mem, mt_term, kappa: float) -> np.ndarray:
-    """Backend-selected batched factor aggregation.
-
-    TPU: Pallas kernel (native lowering).  CPU/GPU: the numpy reference —
-    bit-identical formula, no interpret-mode overhead in the DES hot loop.
-    """
-    if jax.default_backend() == "tpu":
-        return np.asarray(slowdown_factors_pallas(x, beta, mem, mt_term,
-                                                  kappa, interpret=False),
-                          dtype=np.float64)
-    return ref.slowdown_factors_ref(x, beta, mem, mt_term, kappa)
+def slowdown_factors_pallas(x, beta, mem, mt_term, kappa: float, *,
+                            interpret: Optional[bool] = None) -> np.ndarray:
+    """(N, R) pressures -> (N,) float64 factors through the fp32 kernel
+    (interpret mode off-TPU unless ``interpret`` says otherwise)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    x = np.asarray(x, dtype=np.float32)
+    n, r = x.shape
+    nb = bucket(n)
+    rp = r + (-r) % _SUBLANES
+    # zero pressure in padded rclass rows and padded members contributes
+    # a factor term of exactly 1.0; padded members are dropped below
+    xt = np.zeros((rp, nb), np.float32)
+    xt[:r, :n] = x.T
+    betap = np.zeros((rp, 1), np.float32)
+    betap[:r, 0] = beta
+    memp = np.zeros((1, nb), np.float32)
+    memp[0, :n] = mem
+    mtp = np.zeros((1, nb), np.float32)
+    mtp[0, :n] = mt_term
+    out = factors_call(xt, betap, memp, mtp, kappa=float(kappa), n_r=r,
+                       interpret=bool(interpret))
+    return np.asarray(out, dtype=np.float64)[0, :n]

@@ -78,16 +78,23 @@ def rate_advance_pallas(W, rate, t_last, now: float, *,
     Wp = jnp.pad(W, (0, pad)).reshape(rows, cols)
     rp = shape2d(rate)               # pad rate=1: no div-by-zero lanes
     tp = shape2d(t_last)
-    grid = ((rows + bn - 1) // bn,)
     pad_rows = (-rows) % bn
     if pad_rows:
         Wp = jnp.pad(Wp, ((0, pad_rows), (0, 0)))
         rp = jnp.pad(rp, ((0, pad_rows), (0, 0)), constant_values=1.0)
         tp = jnp.pad(tp, ((0, pad_rows), (0, 0)))
-        grid = ((rows + pad_rows) // bn,)
-    out_w, out_e = pl.pallas_call(
+    out_w, out_e = rate_advance_call(Wp, rp, tp, now=now, bn=bn,
+                                     interpret=interpret)
+    return (np.asarray(out_w, np.float64).reshape(-1)[:N],
+            np.asarray(out_e, np.float64).reshape(-1)[:N])
+
+
+def rate_advance_call(Wp, rp, tp, *, now: float, bn: int, interpret: bool):
+    """The padded kernel call over (rows, cols) blocks of ``bn`` rows."""
+    rows, cols = Wp.shape
+    return pl.pallas_call(
         functools.partial(_rate_advance_kernel, now=now),
-        grid=grid,
+        grid=(rows // bn,),
         in_specs=[pl.BlockSpec((bn, cols), lambda i: (i, 0))] * 3,
         out_specs=[pl.BlockSpec((bn, cols), lambda i: (i, 0))] * 2,
         out_shape=[jax.ShapeDtypeStruct(Wp.shape, jnp.float32)] * 2,
@@ -95,8 +102,6 @@ def rate_advance_pallas(W, rate, t_last, now: float, *,
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(Wp, rp, tp)
-    return (np.asarray(out_w, np.float64).reshape(-1)[:N],
-            np.asarray(out_e, np.float64).reshape(-1)[:N])
 
 
 # ---------------------------------------------------------------------------
@@ -130,17 +135,23 @@ def segment_min_pallas(values, counts, *, block_s: int = 256,
     pad_s = (-S) % bs
     dp = jnp.pad(jnp.asarray(dense), ((0, pad_s), (0, pad_e)),
                  constant_values=np.inf)
-    out = pl.pallas_call(
+    out = segment_min_call(dp, bs=bs, interpret=interpret)
+    return np.asarray(out, np.float64)[:S, 0]
+
+
+def segment_min_call(dp, *, bs: int, interpret: bool):
+    """The padded kernel call: (S, E) +inf-padded rows -> (S, 1) minima."""
+    s, e = dp.shape
+    return pl.pallas_call(
         _segment_min_kernel,
-        grid=((S + pad_s) // bs,),
-        in_specs=[pl.BlockSpec((bs, emax + pad_e), lambda i: (i, 0))],
+        grid=(s // bs,),
+        in_specs=[pl.BlockSpec((bs, e), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bs, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((S + pad_s, 1), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s, 1), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(dp)
-    return np.asarray(out, np.float64)[:S, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -23,10 +23,12 @@ and the pu/score decisions never read it).
 
 Dispatch mirrors the other kernels: the numpy reference is the oracle
 and the CPU path; ``REPRO_WALK_KERNEL`` selects ``ref`` | ``jax`` |
-``auto`` (auto takes the jitted path only on an accelerator backend —
-for a reduce this size, XLA on CPU would lose to numpy on dispatch
-overhead alone).  The jax path is jitted over the static plan shapes,
-so repeated scans of one plan reuse the compiled reduce.
+``auto`` (auto takes the jitted path whenever ``jax.default_backend()``
+is not ``cpu`` — for a reduce this size, XLA on CPU would lose to numpy
+on dispatch overhead alone).  The jax path is jitted over the static
+plan shapes, so repeated scans of one plan reuse the compiled reduce.
+It counts in int32 and, without ``jax_enable_x64``, keys and overheads
+in fp32.
 """
 from __future__ import annotations
 
@@ -101,8 +103,8 @@ def _jax_reduce_raw():
     import jax.numpy as jnp
 
     def reduce(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth, lqc):
-        cs = jnp.concatenate([jnp.zeros(1, jnp.int64),
-                              jnp.cumsum(ok.astype(jnp.int64))])
+        cs = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                              jnp.cumsum(ok.astype(jnp.int32))])
         feas = cs[pu_hi] > cs[pu_lo]
         # first feasible index attaining the feasible-row minimum (inf-safe)
         masked = jnp.where(ok, key, jnp.inf)
@@ -125,7 +127,15 @@ def _jax_reduce():
     return jax.jit(_jax_reduce_raw())
 
 
+def _jax_reduce_batch():
+    import jax
+
+    return jax.jit(jax.vmap(_jax_reduce_raw(),
+                            in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None)))
+
+
 _JAX_REDUCE = None
+_JAX_REDUCE_BATCH = None
 _AUTO_JAX = None                          # memoized auto-mode probe
 
 
@@ -139,11 +149,8 @@ def _use_jax() -> bool:
     # auto path costs one env read per call
     global _AUTO_JAX
     if _AUTO_JAX is None:
-        try:
-            import jax
-            _AUTO_JAX = jax.default_backend() not in ("cpu",)
-        except Exception:                 # pragma: no cover - no jax
-            _AUTO_JAX = False
+        import jax
+        _AUTO_JAX = jax.default_backend() != "cpu"
     return _AUTO_JAX
 
 
@@ -160,9 +167,6 @@ def scan_reduce(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth,
         return int(w), int(q), int(h), float(ov)
     return scan_reduce_ref(ok, key, pu_lo, pu_hi, leafcnt, nchild,
                            hopsum, depth, lqc)
-
-
-_JAX_REDUCE_BATCH = None
 
 
 def scan_reduce_batch(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum,
@@ -184,10 +188,7 @@ def scan_reduce_batch(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum,
     if _use_jax():
         global _JAX_REDUCE_BATCH
         if _JAX_REDUCE_BATCH is None:
-            import jax
-            _JAX_REDUCE_BATCH = jax.jit(jax.vmap(
-                _jax_reduce_raw(),
-                in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None)))
+            _JAX_REDUCE_BATCH = _jax_reduce_batch()
         w, q, h, ov = _JAX_REDUCE_BATCH(ok, key, pu_lo, pu_hi, leafcnt,
                                         nchild, hopsum, depth, lqc)
         return (np.asarray(w, dtype=np.int64),

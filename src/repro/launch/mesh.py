@@ -10,28 +10,19 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-
-
-def _auto(n: int) -> dict:
-    """``axis_types`` kwargs for ``jax.make_mesh``.
-
-    ``jax.sharding.AxisType`` only exists on newer JAX; older installs
-    (e.g. 0.4.37) take no ``axis_types`` argument and default to the same
-    Auto behaviour, so simply omit it there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> jax.sharding.Mesh:
-    return jax.make_mesh(shape, axes, **_auto(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -39,7 +30,8 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     n = len(jax.devices())
     model = min(model, n)
     data = max(1, min(data, n // model))
-    return jax.make_mesh((data, model), ("data", "model"), **_auto(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

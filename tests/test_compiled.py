@@ -208,28 +208,77 @@ def test_mark_dead_invalidates_and_reconverges():
         atol=TOL, rtol=TOL)
 
 
-def test_slowdown_kernel_matches_numpy_oracle():
-    """Pallas factor-aggregation kernel (interpret mode) vs ref oracle."""
-    pytest.importorskip("jax")
+@pytest.mark.parametrize("n,r", [(1, 3), (5, 8), (9, 6), (130, 6),
+                                 (300, 6), (2160, 6)])
+def test_slowdown_kernel_matches_numpy_oracle(n, r):
+    """Pallas factor-aggregation kernel (interpret mode) vs ref oracle, at
+    pool sizes that fill their bucket and sizes that pad up to it."""
     from repro.kernels.ref import slowdown_factors_ref
-    from repro.kernels.slowdown_kernel import (slowdown_factors,
-                                               slowdown_factors_pallas)
-    rng = np.random.default_rng(0)
-    for n, r in ((1, 3), (5, 8), (130, 6)):
-        x = rng.uniform(0.0, 3.0, (n, r)) * (rng.random((n, r)) > 0.4)
-        beta = rng.uniform(0.0, 0.5, r)
-        beta[0] = 0.0                       # inactive-resource branch
-        mem = rng.uniform(0.0, 1.0, n)
-        mt = rng.uniform(0.0, 1.0, n) * (rng.random(n) > 0.5)
-        ref = slowdown_factors_ref(x, beta, mem, mt, 0.12)
-        pal = np.asarray(slowdown_factors_pallas(x, beta, mem, mt, 0.12,
-                                                 interpret=True))
-        np.testing.assert_allclose(pal, ref, rtol=2e-5, atol=2e-5)  # fp32
-        # the backend selector must agree with the oracle exactly off-TPU
-        sel = slowdown_factors(x, beta, mem, mt, 0.12)
+    from repro.kernels.slowdown_kernel import slowdown_factors_pallas
+    rng = np.random.default_rng(n * 10 + r)
+    x = rng.uniform(0.0, 3.0, (n, r)) * (rng.random((n, r)) > 0.4)
+    beta = rng.uniform(0.0, 0.5, r)
+    beta[0] = 0.0                           # inactive-resource branch
+    mem = rng.uniform(0.0, 1.0, n)
+    mt = rng.uniform(0.0, 1.0, n) * (rng.random(n) > 0.5)
+    ref = slowdown_factors_ref(x, beta, mem, mt, 0.12)
+    pal = slowdown_factors_pallas(x, beta, mem, mt, 0.12, interpret=True)
+    assert pal.shape == (n,)
+    np.testing.assert_allclose(pal, ref, rtol=1e-6, atol=0)   # fp32
+
+
+@pytest.mark.parametrize("jax_loaded", [True, False])
+def test_aggregate_selects_numpy_on_cpu(monkeypatch, jax_loaded):
+    """Off-TPU the selector picks the float64 numpy path, whatever has
+    been imported already."""
+    monkeypatch.delenv("REPRO_SLOWDOWN_KERNEL", raising=False)
+    if jax_loaded:
         import jax
-        if jax.default_backend() != "tpu":
-            np.testing.assert_array_equal(sel, ref)
+        assert jax.default_backend() == "cpu"
+        from repro.core import slowdown as sdmod
+        assert sdmod._select_aggregate() is sdmod._aggregate_np
+        return
+    import os
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "from repro.core import slowdown as sd\n"
+            "assert 'jax' not in sys.modules\n"
+            "assert sd._select_aggregate() is sd._aggregate_np\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+
+
+def test_walk_reduce_selects_numpy_on_cpu(monkeypatch):
+    from repro.kernels import walk_kernel
+    monkeypatch.delenv("REPRO_WALK_KERNEL", raising=False)
+    monkeypatch.setattr(walk_kernel, "_AUTO_JAX", None)
+    assert walk_kernel._use_jax() is False
+
+
+def test_slowdown_kernel_shapes_bounded_over_serve_run():
+    """The mult=8 serve run's pools come in many sizes; the kernel pads
+    them to power-of-two buckets and compiles at most one shape each."""
+    from benchmarks.serve import _serve_once
+    from repro.core import slowdown as sdmod
+    from repro.kernels import slowdown_kernel as sk
+    sizes, shapes = set(), set()
+
+    def recorded(x, *args):
+        sizes.add(len(x))
+        shapes.add((sk.bucket(len(x)), x.shape[1]))
+        return sk.slowdown_factors_pallas(x, *args, interpret=True)
+
+    sdmod._AGGREGATE = recorded
+    before = sk.factors_call._cache_size()
+    stats, _ = _serve_once(8)
+    compiled = sk.factors_call._cache_size() - before
+    assert stats.engine_opens == 1
+    # powers of two from 8 up to the largest pool
+    n_buckets = int(np.log2(sk.bucket(max(sizes)))) - 2
+    assert len(shapes) <= n_buckets < len(sizes)
+    assert compiled <= len(shapes)
 
 
 def test_set_bandwidth_invalidates_transfer_matrices():
