@@ -55,6 +55,7 @@ from typing import Iterable, Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .hwgraph import HWGraph, ProcessingUnit
 from .task import Task
 from .traverser import TaskPrediction, Traverser
@@ -1066,15 +1067,6 @@ class Orchestrator:
             orc.ledger = led
         self._sharded_hw = shg
 
-    # -- canonical factor-cache visibility (bench JSON / CI smoke) ----------
-    @property
-    def factor_cache_hits(self) -> int:
-        return int(getattr(self.traverser.slowdown, "factor_cache_hits", 0))
-
-    @property
-    def factor_cache_misses(self) -> int:
-        return int(getattr(self.traverser.slowdown, "factor_cache_misses", 0))
-
     def __repr__(self) -> str:
         return f"ORC({self.group})"
 
@@ -1362,6 +1354,7 @@ class Orchestrator:
             fkey = (ctx.core_sig(task), static.single_dev)
             fent = ctx.factor_cache.get(fkey)
             if fent is not None and fent[0] is view and fent[1] is static:
+                trace.count("cache.ident.hit")
                 # identity hit: the device view object survives exactly
                 # while (epoch, version) are unchanged, so the factors —
                 # which never read the clock — are still exact.  Skip the
@@ -1369,6 +1362,7 @@ class Orchestrator:
                 # constraint block below re-reads ``now``
                 fused = (fent[2], view)
             else:
+                trace.count("cache.ident.miss")
                 canon = getattr(sd, "_canon_key", None)
                 if canon is not None:
                     key, _ = canon(ctx.comp, task, static.cand_idx,
@@ -1382,8 +1376,10 @@ class Orchestrator:
                               view.rel.tobytes())
                         hit = ctx.splice_cache.get(ck)
                         if hit is not None:
+                            trace.count("cache.splice.hit")
                             return (hit[0].copy(), hit[1].copy(),
                                     hit[2].copy(), hit[3].copy(), hit[4])
+                        trace.count("cache.splice.miss")
         ok = np.zeros(n, dtype=bool)
         sa = np.full(n, np.inf)
         f = np.ones(n)
@@ -1501,6 +1497,7 @@ class Orchestrator:
         ck = (ctx.task_sig(task), id(plan.pus))
         ent = ctx.eff_cache.get(ck)
         if ent is not None and ent[0] is st:
+            trace.count("cache.eff.hit")
             pos, rpos, ok, cm, key = ent[1], ent[2], ent[3], ent[4], ent[5]
             if pos < len(log) or rpos < len(rlog):
                 # union of the commit suffix and the scan state's own
@@ -1524,6 +1521,7 @@ class Orchestrator:
                 ent[1] = len(log)
                 ent[2] = len(rlog)
             return ok, cm, key
+        trace.count("cache.eff.miss")
         cm = np.zeros(len(plan.pus))
         if len(cols):
             cm[cols] = static.comm + st.wait[cols]

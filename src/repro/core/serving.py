@@ -51,6 +51,7 @@ from .hwgraph import HWGraph
 from .orchestrator import Orchestrator
 from .session import Policy, SchedulerSession, percentiles
 from .task import Task, TaskGraph
+from .trace import span
 from .traverser import Traverser
 
 
@@ -484,9 +485,9 @@ class ServeLoop:
             return
         for req in live:
             self.session.submit(req.graph)
-        w0 = _time.perf_counter()
-        results = self.session.map_pending(fallback=False)
-        self.phase_wall["map"] += _time.perf_counter() - w0
+        with span("serve.map") as sp:
+            results = self.session.map_pending(fallback=False)
+        self.phase_wall["map"] += sp.wall
         proj = 0.0
         for req in live:
             rs = [results.get(t.uid) for t in req.tasks]
@@ -548,56 +549,60 @@ class ServeLoop:
                 # so re-read the target each step.  (When nothing is due,
                 # the advance call — which would only park the clock —
                 # is skipped entirely: the idle fast path.)
-                w0 = _time.perf_counter()
-                self.engine.advance(tn)
-                w1 = _time.perf_counter()
-                self._sync_completions()
-                w2 = _time.perf_counter()
-                pw["advance"] += w1 - w0
-                pw["sync"] += w2 - w1
+                with span("timeline.advance") as adv:
+                    self.engine.advance(tn)
+                with span("serve.sync") as syn:
+                    self._sync_completions()
+                pw["advance"] += adv.wall
+                pw["sync"] += syn.wall
                 continue
             if not events:
                 break
-            t0 = events[0][0]
-            now = t0
-            window = bw.window(sum(self._inflight.values()),
-                               self._last_proj) if adaptive else bw
-            wave: list[ServeRequest] = []
-            while events and events[0][0] <= t0 + window:
-                t, kind, rid, payload = heapq.heappop(events)
-                now = t
-                if kind == 0:
-                    ti, client = payload
-                    spec = self.tenants[ti]
-                    g = spec.make_request(rid // len(self.tenants), t)
-                    tasks = list(g)
-                    for task in tasks:
-                        task.attrs.setdefault("tenant", spec.name)
-                        task.attrs["request"] = rid
-                    req = ServeRequest(tenant=spec.name, rid=rid,
-                                       arrival=t, graph=g, tasks=tasks,
-                                       sla=spec.sla,
-                                       max_inflight=spec.max_inflight,
-                                       client=client)
-                    self.requests.append(req)
-                else:
-                    req = payload
-                wave.append(req)
-            # admit at the arrival instant: every engine event strictly
-            # before the wave's earliest arrival has drained above, so
-            # injected releases enter the heap ahead of the clock — same
-            # event order as a one-shot run (with a window, occupancy is
-            # as of t0, slightly stale for the later arrivals it
-            # coalesced)
-            w0 = _time.perf_counter()
-            self._sync_completions()
-            w1 = _time.perf_counter()
-            m0 = pw["map"]
-            self._admit_wave(now, wave, events)
-            w2 = _time.perf_counter()
-            pw["sync"] += w1 - w0
-            pw["admit"] += (w2 - w1) - (pw["map"] - m0)
-            self.wave_sizes.append(len(wave))
+            # one wave: from its first popped arrival through admission;
+            # the span's arguments tie a reading's spans together in a
+            # profile (wave index, first request id, readings)
+            with span("serve.wave", wave=len(self.wave_sizes),
+                      rid=events[0][2]) as wsp:
+                t0 = events[0][0]
+                now = t0
+                window = bw.window(sum(self._inflight.values()),
+                                   self._last_proj) if adaptive else bw
+                wave: list[ServeRequest] = []
+                while events and events[0][0] <= t0 + window:
+                    t, kind, rid, payload = heapq.heappop(events)
+                    now = t
+                    if kind == 0:
+                        ti, client = payload
+                        spec = self.tenants[ti]
+                        g = spec.make_request(rid // len(self.tenants), t)
+                        tasks = list(g)
+                        for task in tasks:
+                            task.attrs.setdefault("tenant", spec.name)
+                            task.attrs["request"] = rid
+                        req = ServeRequest(tenant=spec.name, rid=rid,
+                                           arrival=t, graph=g, tasks=tasks,
+                                           sla=spec.sla,
+                                           max_inflight=spec.max_inflight,
+                                           client=client)
+                        self.requests.append(req)
+                    else:
+                        req = payload
+                    wave.append(req)
+                wsp.note(readings=len(wave))
+                # admit at the arrival instant: every engine event
+                # strictly before the wave's earliest arrival has drained
+                # above, so injected releases enter the heap ahead of the
+                # clock — same event order as a one-shot run (with a
+                # window, occupancy is as of t0, slightly stale for the
+                # later arrivals it coalesced)
+                with span("serve.sync") as syn:
+                    self._sync_completions()
+                m0 = pw["map"]
+                with span("serve.admit") as adm:
+                    self._admit_wave(now, wave, events)
+                pw["sync"] += syn.wall
+                pw["admit"] += adm.wall - (pw["map"] - m0)
+                self.wave_sizes.append(len(wave))
         wall = _time.perf_counter() - wall0
         return ServeStats(requests=list(self.requests),
                           horizon=self.horizon, wall_s=wall,

@@ -50,12 +50,12 @@ computes in fp32 (within 1e-6 relative of the reference).
 from __future__ import annotations
 
 import os
-import threading
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .hwgraph import HWGraph
 from .task import Task
 
@@ -180,14 +180,10 @@ class DecoupledSlowdown:
         self._tables_cache: Optional[tuple] = None
         # canonical-pattern result cache for single-device constraint
         # checks (see _canon_key); keyed per snapshot identity like the
-        # tables, plus hit/miss counters surfaced in the benchmarks
+        # tables.  The sharded walk's group threads share it: fills are
+        # idempotent equal values, and its hits and misses count in
+        # ``trace`` (``cache.canon.hit`` / ``.miss``)
         self._canon_cache: Optional[tuple] = None
-        self.factor_cache_hits = 0
-        self.factor_cache_misses = 0
-        # the sharded walk drives group threads through the canon cache
-        # concurrently; the counter read-modify-writes are the only
-        # non-atomic mutations (cache fills are idempotent equal values)
-        self._counter_lock = threading.Lock()
 
     # -- helpers -----------------------------------------------------------
     def nearest_shared(self, pu_a: str, pu_b: str) -> Optional[str]:
@@ -334,27 +330,28 @@ class DecoupledSlowdown:
         devices factors exactly as the per-device pools would
         (cross-device pairs share nothing by construction).  Noise-free
         path only (the engine routes noisy models to the tuple surface)."""
-        n = len(P)
-        if n == 0:
-            return np.ones(0)
-        comp = self.graph.compiled()
-        if n == 1:
-            return np.ones(1)          # a lone job has no co-runners
-        M = np.minimum(mem, comp.mem_cap[P])
-        if n == 2:
-            # scalar pair path: light-load DES pools are mostly pairs, and
-            # the float ops replicate the array path bit-for-bit (a row's
-            # product over inactive rclasses multiplies exact 1.0s)
-            return self._factor_pair(comp, P, U, M)
-        if n <= _SMALL_POOL_MAX:
-            # light-load pools floor on array-path call overhead (bincount,
-            # nonzero, broadcasting all cost more than the math below this
-            # size); the scalar loop replicates the array path bit-for-bit
-            return self._factor_small(comp, P, U, M)
-        # DES pools hold one job per task, so uids are pairwise distinct:
-        # self-interaction reduces to the diagonal and the uid mask work
-        # is skipped entirely
-        return self._factor_batch_arrays(comp, P, U, M, uid, distinct=True)
+        with trace.span("slowdown.score"):
+            n = len(P)
+            if n == 0:
+                return np.ones(0)
+            comp = self.graph.compiled()
+            if n == 1:
+                return np.ones(1)          # a lone job has no co-runners
+            M = np.minimum(mem, comp.mem_cap[P])
+            if n == 2:
+                # scalar pair path: light-load DES pools are mostly pairs, and
+                # the float ops replicate the array path bit-for-bit (a row's
+                # product over inactive rclasses multiplies exact 1.0s)
+                return self._factor_pair(comp, P, U, M)
+            if n <= _SMALL_POOL_MAX:
+                # light-load pools floor on array-path call overhead (bincount,
+                # nonzero, broadcasting all cost more than the math below this
+                # size); the scalar loop replicates the array path bit-for-bit
+                return self._factor_small(comp, P, U, M)
+            # DES pools hold one job per task, so uids are pairwise distinct:
+            # self-interaction reduces to the diagonal and the uid mask work
+            # is skipped entirely
+            return self._factor_batch_arrays(comp, P, U, M, uid, distinct=True)
 
     def _factor_pair(self, comp, P, U, M) -> np.ndarray:
         beta_vec, mt_vec = self._tables(comp)
@@ -593,30 +590,31 @@ class DecoupledSlowdown:
         the rows of every distinct task signature in a mapping wave and
         aggregate the whole frontier in a single kernel call.
         """
-        empty = np.zeros(0, dtype=np.int64)
-        if len(Pc) == 0 or len(Pa) == 0:
-            return np.ones(len(Pc)), empty, empty, np.ones(0)
-        key, base = self._canon_key(comp, task, Pc, Dc, Pa, Ua, Ma, uid_a,
-                                    astart, na)
-        if key is not None:
-            hit = self._canon_lookup(comp, key, base)
-            if hit is not None:
-                return hit
-        rows = self._same_device_rows(comp, task, Pc, Dc, Pa, Ua, Ma,
-                                      uid_a, Da, astart, na)
-        if rows is None:
-            # no active shares a device with any candidate: all factors 1
-            out = (np.ones(len(Pc)), empty, empty, np.ones(0))
-        else:
-            X, mem, mt_term, ci, ai = rows
-            beta_vec, _ = self._tables(comp)
-            C = len(Pc)
-            f = _aggregate(X, beta_vec, mem, mt_term,
-                           self.params.superlinear)
-            out = (f[:C], ci, ai, f[C:])
-        if key is not None:
-            self._canon_store(key, base, out)
-        return out
+        with trace.span("slowdown.score"):
+            empty = np.zeros(0, dtype=np.int64)
+            if len(Pc) == 0 or len(Pa) == 0:
+                return np.ones(len(Pc)), empty, empty, np.ones(0)
+            key, base = self._canon_key(comp, task, Pc, Dc, Pa, Ua, Ma, uid_a,
+                                        astart, na)
+            if key is not None:
+                hit = self._canon_lookup(comp, key, base)
+                if hit is not None:
+                    return hit
+            rows = self._same_device_rows(comp, task, Pc, Dc, Pa, Ua, Ma,
+                                          uid_a, Da, astart, na)
+            if rows is None:
+                # no active shares a device with any candidate: all factors 1
+                out = (np.ones(len(Pc)), empty, empty, np.ones(0))
+            else:
+                X, mem, mt_term, ci, ai = rows
+                beta_vec, _ = self._tables(comp)
+                C = len(Pc)
+                f = _aggregate(X, beta_vec, mem, mt_term,
+                               self.params.superlinear)
+                out = (f[:C], ci, ai, f[C:])
+            if key is not None:
+                self._canon_store(key, base, out)
+            return out
 
     def factors_same_device_multi(self, comp, items: Sequence[tuple]):
         """Score many newcomers (one per distinct wave signature) in one
@@ -625,53 +623,54 @@ class DecoupledSlowdown:
         method's return tuple per item, bit-for-bit identical to calling
         it per item (the kernel is elementwise per row, so stacking and
         splitting is exact)."""
-        empty = np.zeros(0, dtype=np.int64)
-        built: list = []
-        blocks: list = []
-        keys: list = []
-        for it in items:
-            if len(it[1]) == 0 or len(it[3]) == 0:
-                built.append(None)
-                keys.append(None)
-                continue
-            key, base = self._canon_key(comp, it[0], it[1], it[2], it[3],
-                                        it[4], it[5], it[6], it[8], it[9])
-            if key is not None:
-                hit = self._canon_lookup(comp, key, base)
-                if hit is not None:
-                    built.append(hit)
-                    keys.append(None)       # already cached
+        with trace.span("slowdown.score"):
+            empty = np.zeros(0, dtype=np.int64)
+            built: list = []
+            blocks: list = []
+            keys: list = []
+            for it in items:
+                if len(it[1]) == 0 or len(it[3]) == 0:
+                    built.append(None)
+                    keys.append(None)
                     continue
-            keys.append((key, base))
-            rows = self._same_device_rows(comp, *it)
-            built.append(rows)
-            if rows is not None:
-                blocks.append(rows)
-        if blocks:
-            beta_vec, _ = self._tables(comp)
-            f = _aggregate(np.concatenate([b[0] for b in blocks]),
-                           beta_vec,
-                           np.concatenate([b[1] for b in blocks]),
-                           np.concatenate([b[2] for b in blocks]),
-                           self.params.superlinear)
-        pos = 0
-        out = []
-        for it, rows, kb in zip(items, built, keys):
-            C = len(it[1])
-            if isinstance(rows, tuple) and len(rows) == 4:
-                out.append(rows)            # cache hit, already final
-                continue
-            if rows is None:
-                res = (np.ones(C), empty, empty, np.ones(0))
-            else:
-                k = len(rows[1])
-                fi = f[pos:pos + k]
-                pos += k
-                res = (fi[:C], rows[3], rows[4], fi[C:])
-            if kb is not None and kb[0] is not None:
-                self._canon_store(kb[0], kb[1], res)
-            out.append(res)
-        return out
+                key, base = self._canon_key(comp, it[0], it[1], it[2], it[3],
+                                            it[4], it[5], it[6], it[8], it[9])
+                if key is not None:
+                    hit = self._canon_lookup(comp, key, base)
+                    if hit is not None:
+                        built.append(hit)
+                        keys.append(None)       # already cached
+                        continue
+                keys.append((key, base))
+                rows = self._same_device_rows(comp, *it)
+                built.append(rows)
+                if rows is not None:
+                    blocks.append(rows)
+            if blocks:
+                beta_vec, _ = self._tables(comp)
+                f = _aggregate(np.concatenate([b[0] for b in blocks]),
+                               beta_vec,
+                               np.concatenate([b[1] for b in blocks]),
+                               np.concatenate([b[2] for b in blocks]),
+                               self.params.superlinear)
+            pos = 0
+            out = []
+            for it, rows, kb in zip(items, built, keys):
+                C = len(it[1])
+                if isinstance(rows, tuple) and len(rows) == 4:
+                    out.append(rows)            # cache hit, already final
+                    continue
+                if rows is None:
+                    res = (np.ones(C), empty, empty, np.ones(0))
+                else:
+                    k = len(rows[1])
+                    fi = f[pos:pos + k]
+                    pos += k
+                    res = (fi[:C], rows[3], rows[4], fi[C:])
+                if kb is not None and kb[0] is not None:
+                    self._canon_store(kb[0], kb[1], res)
+                out.append(res)
+            return out
 
     # -- canonical-pattern cache (single-device constraint checks) ---------
     def _canon_key(self, comp, task: Task, Pc, Dc, Pa, Ua, Ma, uid_a,
@@ -729,17 +728,15 @@ class DecoupledSlowdown:
     def _canon_lookup(self, comp, key, base):
         hit = self._canon_cache_dict(comp).get(key)
         if hit is None:
+            trace.count("cache.canon.miss")
             return None
-        with self._counter_lock:
-            self.factor_cache_hits += 1
+        trace.count("cache.canon.hit")
         new_f, ci, rel_ai, act_pf = hit
         return new_f, ci, rel_ai + base, act_pf
 
     def _canon_store(self, key, base, result) -> None:
         # _canon_lookup always ran first, so the per-snapshot dict exists
         cache = self._canon_cache[1]
-        with self._counter_lock:
-            self.factor_cache_misses += 1
         if len(cache) > 100_000:            # runaway-key backstop
             cache.clear()
         new_f, ci, ai, act_pf = result
@@ -821,9 +818,6 @@ class DecoupledSlowdown:
 
 class NoSlowdown:
     """Contention-blind model (what ACE-like baselines assume)."""
-
-    factor_cache_hits = 0
-    factor_cache_misses = 0
 
     def __init__(self, graph: HWGraph, *a, **k) -> None:
         self.graph = graph

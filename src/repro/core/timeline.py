@@ -75,6 +75,7 @@ from typing import Any, Callable, Optional, Sequence
 
 import numpy as np
 
+from . import trace
 from .hwgraph import EdgeAttr, ProcessingUnit
 from .task import Task, TaskGraph
 
@@ -736,6 +737,12 @@ class TimelineEngine:
         """Reprice every dirty device pool (one factor call) and every
         dirty link set (one segment-min).  Returns True when any rate was
         re-projected — i.e. when same-timestamp work may now exist."""
+        if not (self.dirty_devs or self.dirty_edges):
+            return False
+        with trace.span("timeline.flush"):
+            return self._reprice()
+
+    def _reprice(self) -> bool:
         t = self.time
         flushed = False
         if self.dirty_devs:

@@ -30,6 +30,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core import trace
+
 _SUBLANES = 8
 _MIN_BUCKET = 8
 _MAX_BLOCK = 2048          # lanes per grid step; larger buckets tile
@@ -85,20 +87,26 @@ def slowdown_factors_pallas(x, beta, mem, mt_term, kappa: float, *,
     (interpret mode off-TPU unless ``interpret`` says otherwise)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    x = np.asarray(x, dtype=np.float32)
-    n, r = x.shape
-    nb = bucket(n)
-    rp = r + (-r) % _SUBLANES
-    # zero pressure in padded rclass rows and padded members contributes
-    # a factor term of exactly 1.0; padded members are dropped below
-    xt = np.zeros((rp, nb), np.float32)
-    xt[:r, :n] = x.T
-    betap = np.zeros((rp, 1), np.float32)
-    betap[:r, 0] = beta
-    memp = np.zeros((1, nb), np.float32)
-    memp[0, :n] = mem
-    mtp = np.zeros((1, nb), np.float32)
-    mtp[0, :n] = mt_term
-    out = factors_call(xt, betap, memp, mtp, kappa=float(kappa), n_r=r,
-                       interpret=bool(interpret))
-    return np.asarray(out, dtype=np.float64)[0, :n]
+    with trace.span("device.slowdown"):
+        x = np.asarray(x, dtype=np.float32)
+        n, r = x.shape
+        nb = bucket(n)
+        rp = r + (-r) % _SUBLANES
+        # zero pressure in padded rclass rows and padded members
+        # contributes a factor term of exactly 1.0; padded members are
+        # dropped below
+        xt = np.zeros((rp, nb), np.float32)
+        xt[:r, :n] = x.T
+        betap = np.zeros((rp, 1), np.float32)
+        betap[:r, 0] = beta
+        memp = np.zeros((1, nb), np.float32)
+        memp[0, :n] = mem
+        mtp = np.zeros((1, nb), np.float32)
+        mtp[0, :n] = mt_term
+        with trace.span("device.slowdown.call"):
+            trace.count("device.h2d", 4)
+            out = factors_call(xt, betap, memp, mtp, kappa=float(kappa),
+                               n_r=r, interpret=bool(interpret))
+        with trace.span("device.slowdown.fetch"):
+            trace.count("device.fetch", 1)
+            return np.asarray(out, dtype=np.float64)[0, :n]

@@ -37,6 +37,8 @@ from typing import Tuple
 
 import numpy as np
 
+from ..core import trace
+
 __all__ = ["scan_reduce", "scan_reduce_batch", "scan_reduce_ref"]
 
 
@@ -162,9 +164,14 @@ def scan_reduce(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth,
         global _JAX_REDUCE
         if _JAX_REDUCE is None:
             _JAX_REDUCE = _jax_reduce()
-        w, q, h, ov = _JAX_REDUCE(ok, key, pu_lo, pu_hi, leafcnt, nchild,
-                                  hopsum, depth, lqc)
-        return int(w), int(q), int(h), float(ov)
+        with trace.span("device.walk_reduce"):
+            with trace.span("device.walk_reduce.call"):
+                trace.count("device.h2d", 9)
+                w, q, h, ov = _JAX_REDUCE(ok, key, pu_lo, pu_hi, leafcnt,
+                                          nchild, hopsum, depth, lqc)
+            with trace.span("device.walk_reduce.fetch"):
+                trace.count("device.fetch", 4)
+                return int(w), int(q), int(h), float(ov)
     return scan_reduce_ref(ok, key, pu_lo, pu_hi, leafcnt, nchild,
                            hopsum, depth, lqc)
 
@@ -189,12 +196,18 @@ def scan_reduce_batch(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum,
         global _JAX_REDUCE_BATCH
         if _JAX_REDUCE_BATCH is None:
             _JAX_REDUCE_BATCH = _jax_reduce_batch()
-        w, q, h, ov = _JAX_REDUCE_BATCH(ok, key, pu_lo, pu_hi, leafcnt,
-                                        nchild, hopsum, depth, lqc)
-        return (np.asarray(w, dtype=np.int64),
-                np.asarray(q, dtype=np.int64),
-                np.asarray(h, dtype=np.int64),
-                np.asarray(ov, dtype=np.float64))
+        with trace.span("device.walk_reduce_batch"):
+            with trace.span("device.walk_reduce_batch.call"):
+                trace.count("device.h2d", 9)
+                w, q, h, ov = _JAX_REDUCE_BATCH(ok, key, pu_lo, pu_hi,
+                                                leafcnt, nchild, hopsum,
+                                                depth, lqc)
+            with trace.span("device.walk_reduce_batch.fetch"):
+                trace.count("device.fetch", 4)
+                return (np.asarray(w, dtype=np.int64),
+                        np.asarray(q, dtype=np.int64),
+                        np.asarray(h, dtype=np.int64),
+                        np.asarray(ov, dtype=np.float64))
     n = len(ok)
     winners = np.empty(n, dtype=np.int64)
     queries = np.empty(n, dtype=np.int64)

@@ -5,7 +5,8 @@ sequential per-task oracle (``REPRO_FUSED_WALK=0``)."""
 import pytest
 
 from repro.core import (ActiveLedger, OrcConfig, Orchestrator, Traverser,
-                        build_orchestrators, build_testbed, heye_traverser)
+                        build_orchestrators, build_testbed, heye_traverser,
+                        trace)
 from repro.core.topology import make_task
 
 
@@ -390,6 +391,7 @@ def test_sharded_session_state(monkeypatch):
     assert len(root.ledger.shards) == len(root.children) >= 2
     # every device ORC routes through the same sharded ledger facade
     assert all(o.ledger is root.ledger for o in root.iter_tree())
+    c0 = trace.snapshot()["counters"]
     sess.submit(mining_workload(tb, n_sensors=8, n_readings=1))
     res = sess.map_pending()
     assert res and all(r is not None for r in res.values())
@@ -397,7 +399,9 @@ def test_sharded_session_state(monkeypatch):
     assert len(root.ledger) == sum(len(s) for s in root.ledger.shards)
     assert len(root.ledger) == len(res)
     # shared counters see the whole run, not one shard's slice
-    assert root.factor_cache_hits + root.factor_cache_misses > 0
+    c1 = trace.snapshot()["counters"]
+    assert sum(c1.get(k, 0) - c0.get(k, 0)
+               for k in ("cache.canon.hit", "cache.canon.miss")) > 0
     # sharding never forces extra snapshot recompiles
     assert tb.graph.recompile_count <= 1
     stats = sess.execute()

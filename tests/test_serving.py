@@ -685,3 +685,44 @@ def test_closed_loop_serving_deterministic_and_self_clocked():
             bound = prev.finish if prev.finish == prev.finish \
                 else prev.arrival
             assert nxt.arrival >= bound - TOL
+
+
+# ---------------------------------------------------------------------------
+# the loop's spans and the walk's cache counters (repro.core.trace)
+# ---------------------------------------------------------------------------
+def test_loop_spans_feed_phase_walls_and_count_caches():
+    """``phase_wall`` is read off the loop's own spans: one ``serve.wave``
+    per admission wave, and the map and advance phases equal to the
+    ``serve.map`` / ``timeline.advance`` walls.  The walk's caches count
+    their lookups on the serving fast path."""
+    from repro.core import trace
+    s0 = trace.snapshot()
+    stats = _serve_run(760_000)
+    s1 = trace.snapshot()
+
+    def span(name):
+        a = s0["spans"].get(name, (0, 0.0, 0.0))
+        return tuple(x - y for x, y in zip(s1["spans"][name], a))
+
+    pw = stats.phase_wall
+    assert span("serve.wave")[0] == len(stats.wave_sizes)
+    assert span("serve.admit")[0] == len(stats.wave_sizes)
+    assert span("serve.map")[1] == pytest.approx(pw["map"], rel=1e-9)
+    assert span("timeline.advance")[1] == pytest.approx(pw["advance"],
+                                                        rel=1e-9)
+    assert span("serve.sync")[1] == pytest.approx(pw["sync"], rel=1e-9)
+    assert span("serve.admit")[1] == pytest.approx(pw["admit"] + pw["map"],
+                                                   rel=1e-9)
+    # a wave holds its admission (and one sync); waves and the advances
+    # between them never overlap, so together they fit in the run's wall
+    assert span("serve.admit")[1] <= span("serve.wave")[1]
+    assert 0.0 <= span("serve.wave")[2] <= span("serve.wave")[1]
+    top = span("serve.wave")[1] + span("timeline.advance")[1]
+    assert top <= stats.wall_s
+    assert span("timeline.flush")[0] > 0
+    assert span("slowdown.score")[0] > 0
+    for cache in ("eff", "ident", "canon"):
+        looked = sum(s1["counters"].get(f"cache.{cache}.{k}", 0)
+                     - s0["counters"].get(f"cache.{cache}.{k}", 0)
+                     for k in ("hit", "miss"))
+        assert looked > 0, cache
