@@ -13,7 +13,12 @@ run along the lanes, so the output is one lane-dense ``(1, N)`` row.  The
 product over rclasses is unrolled over the real rclass rows (Mosaic has
 no ``reduce_prod`` lowering).  Pools are padded to a power-of-two width
 (:func:`bucket`), so a run compiles one kernel per power of two its pools
-reach; padded members are dropped after the call.
+reach; padded members are dropped after the call.  A pool wider than
+``MAX_BUCKET`` goes through in slices of that width (the map is
+elementwise per member, so slicing changes no bit), which bounds the
+kernel to the buckets from 8 to ``MAX_BUCKET``: a serving process warmed
+on those never compiles again, however large a lockstep escalation's
+batched check grows.
 
 ``core.slowdown`` selects this kernel on a TPU backend and the float64
 numpy reference (``ref.slowdown_factors_ref``) everywhere else.  Off-TPU
@@ -35,6 +40,7 @@ from ..core import trace
 _SUBLANES = 8
 _MIN_BUCKET = 8
 _MAX_BLOCK = 2048          # lanes per grid step; larger buckets tile
+MAX_BUCKET = 8192          # widest pool one call takes; wider pools slice
 
 
 def bucket(n: int) -> int:
@@ -84,7 +90,15 @@ def factors_call(xt, beta, mem, mt, *, kappa: float, n_r: int,
 def slowdown_factors_pallas(x, beta, mem, mt_term, kappa: float, *,
                             interpret: Optional[bool] = None) -> np.ndarray:
     """(N, R) pressures -> (N,) float64 factors through the fp32 kernel
-    (interpret mode off-TPU unless ``interpret`` says otherwise)."""
+    (interpret mode off-TPU unless ``interpret`` says otherwise), one call
+    per ``MAX_BUCKET`` members."""
+    if len(x) > MAX_BUCKET:
+        return np.concatenate([
+            slowdown_factors_pallas(x[i:i + MAX_BUCKET], beta,
+                                    mem[i:i + MAX_BUCKET],
+                                    mt_term[i:i + MAX_BUCKET], kappa,
+                                    interpret=interpret)
+            for i in range(0, len(x), MAX_BUCKET)])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     with trace.span("device.slowdown"):

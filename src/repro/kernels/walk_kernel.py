@@ -27,12 +27,24 @@ and the CPU path; ``REPRO_WALK_KERNEL`` selects ``ref`` | ``jax`` |
 is not ``cpu`` — for a reduce this size, XLA on CPU would lose to numpy
 on dispatch overhead alone).  The jax path is jitted over the static
 plan shapes, so repeated scans of one plan reuse the compiled reduce.
-It counts in int32 and, without ``jax_enable_x64``, keys and overheads
-in fp32.
+It counts in int32 and computes keys and overheads in fp32.
+
+A device call costs per value handed over and per blocking read, not
+per operation, so each jax call hands over one array and reads one
+back.  The plan's six columns and ``lqc`` are packed into one int32
+array and kept on the device, keyed by their content (a bounded LRU,
+shared by every plan with equal columns; a plan rebuilt with new values
+misses, never reads stale constants); a call uploads only ``ok`` and
+``key``, packed in fp32, and reads ``(winner, queries, hops, overhead's
+fp32 bits)`` back as one int32 vector (``(rows, 4)`` batched).  All
+device arithmetic is inside the two jitted programs, so a new plan of a
+shape already seen compiles nothing.
 """
 from __future__ import annotations
 
 import os
+import threading
+from collections import OrderedDict
 from typing import Tuple
 
 import numpy as np
@@ -101,10 +113,12 @@ def scan_reduce_ref(ok: np.ndarray, key: np.ndarray, pu_lo: np.ndarray,
     return w, queries, hops, overhead
 
 
-def _jax_reduce_raw():
+def _scan_math():
+    """The reduce over its nine operands in jnp; traced only inside the
+    jitted programs."""
     import jax.numpy as jnp
 
-    def reduce(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth, lqc):
+    def scan(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth, lqc):
         cs = jnp.concatenate([jnp.zeros(1, jnp.int32),
                               jnp.cumsum(ok.astype(jnp.int32))])
         feas = cs[pu_hi] > cs[pu_lo]
@@ -120,6 +134,29 @@ def _jax_reduce_raw():
             feas, hopsum + lqc * leafcnt * (depth + 1.0), 0.0))
         return w, queries, hops, overhead
 
+    return scan
+
+
+def _jax_reduce_raw():
+    import jax.numpy as jnp
+    from jax import lax
+
+    scan = _scan_math()
+
+    def reduce(data, plan):
+        """``data`` is :func:`_pack_scans`'s ``(2, n)``, ``plan``
+        :func:`_pack_plan`'s ``(6m + 1,)``; returns ``(winner, queries,
+        hops, overhead's fp32 bits)`` as one int32 vector."""
+        m = (plan.shape[-1] - 1) // 6
+        f32 = lax.bitcast_convert_type(plan[4 * m:], jnp.float32)
+        w, q, h, ov = scan(data[0] != 0, data[1], plan[:m], plan[m:2 * m],
+                           plan[2 * m:3 * m], plan[3 * m:4 * m], f32[:m],
+                           f32[m:2 * m], f32[2 * m])
+        return jnp.stack([w.astype(jnp.int32), q.astype(jnp.int32),
+                          h.astype(jnp.int32),
+                          lax.bitcast_convert_type(ov.astype(jnp.float32),
+                                                   jnp.int32)])
+
     return reduce
 
 
@@ -132,13 +169,73 @@ def _jax_reduce():
 def _jax_reduce_batch():
     import jax
 
-    return jax.jit(jax.vmap(_jax_reduce_raw(),
-                            in_axes=(0, 0, 0, 0, 0, 0, 0, 0, None)))
+    return jax.jit(jax.vmap(_jax_reduce_raw()))
+
+
+def _pack_scans(ok, key) -> np.ndarray:
+    """The per-call operands in one fp32 array, ``(..., 2, n)``: ``ok`` as
+    0/1, then the keys rounded to fp32."""
+    ok = np.asarray(ok)
+    data = np.empty(ok.shape[:-1] + (2, ok.shape[-1]), dtype=np.float32)
+    data[..., 0, :] = ok
+    data[..., 1, :] = key
+    return data
+
+
+def _pack_plan(pu_lo, pu_hi, leafcnt, nchild, hopsum, depth,
+               lqc: float) -> np.ndarray:
+    """The plan constants in one int32 array, ``(..., 6m + 1)``:
+    ``pu_lo``, ``pu_hi``, ``leafcnt``, ``nchild``, then the fp32 bits of
+    ``hopsum``, ``depth`` and ``lqc``."""
+    m = np.shape(pu_lo)[-1]
+    out = np.empty(np.shape(pu_lo)[:-1] + (6 * m + 1,), dtype=np.int32)
+    for i, c in enumerate((pu_lo, pu_hi, leafcnt, nchild)):
+        out[..., i * m:(i + 1) * m] = c
+    f32 = out[..., 4 * m:].view(np.float32)
+    f32[..., :m] = hopsum
+    f32[..., m:2 * m] = depth
+    f32[..., 2 * m] = lqc
+    return out
+
+
+class _ResidentPlans:
+    """Device-resident packed plan constants, keyed by their content (the
+    bytes, dtype and shape of each column, and ``lqc``), least recently
+    used first out.  A rebuilt plan with new values finds nothing stale,
+    and plans with equal columns share one copy."""
+
+    def __init__(self, capacity: int) -> None:
+        self.capacity = capacity
+        self._dev: OrderedDict = OrderedDict()
+        self._lock = threading.Lock()     # the sharded walk's group threads
+
+    def get(self, cols, lqc: float):
+        cols = [np.asarray(c) for c in cols]
+        key = (float(lqc),) + tuple((c.dtype.str, c.shape, c.tobytes())
+                                    for c in cols)
+        with self._lock:
+            dev = self._dev.get(key)
+            if dev is not None:
+                self._dev.move_to_end(key)
+        if dev is not None:
+            trace.count("cache.plan_dev.hit")
+            return dev
+        import jax
+        trace.count("cache.plan_dev.miss")
+        trace.count("device.h2d")
+        dev = jax.device_put(_pack_plan(*cols, lqc))
+        with self._lock:
+            self._dev[key] = dev
+            while len(self._dev) > self.capacity:
+                self._dev.popitem(last=False)
+        return dev
 
 
 _JAX_REDUCE = None
 _JAX_REDUCE_BATCH = None
 _AUTO_JAX = None                          # memoized auto-mode probe
+# well above the distinct plans and batched stacks of a fleet's walk
+_PLANS = _ResidentPlans(4096)
 
 
 def _use_jax() -> bool:
@@ -166,12 +263,15 @@ def scan_reduce(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum, depth,
             _JAX_REDUCE = _jax_reduce()
         with trace.span("device.walk_reduce"):
             with trace.span("device.walk_reduce.call"):
-                trace.count("device.h2d", 9)
-                w, q, h, ov = _JAX_REDUCE(ok, key, pu_lo, pu_hi, leafcnt,
-                                          nchild, hopsum, depth, lqc)
+                plan = _PLANS.get((pu_lo, pu_hi, leafcnt, nchild, hopsum,
+                                   depth), lqc)
+                trace.count("device.h2d")
+                out = _JAX_REDUCE(_pack_scans(ok, key), plan)
             with trace.span("device.walk_reduce.fetch"):
-                trace.count("device.fetch", 4)
-                return int(w), int(q), int(h), float(ov)
+                trace.count("device.fetch")
+                a = np.asarray(out)
+                return (int(a[0]), int(a[1]), int(a[2]),
+                        float(a.view(np.float32)[3]))
     return scan_reduce_ref(ok, key, pu_lo, pu_hi, leafcnt, nchild,
                            hopsum, depth, lqc)
 
@@ -198,16 +298,16 @@ def scan_reduce_batch(ok, key, pu_lo, pu_hi, leafcnt, nchild, hopsum,
             _JAX_REDUCE_BATCH = _jax_reduce_batch()
         with trace.span("device.walk_reduce_batch"):
             with trace.span("device.walk_reduce_batch.call"):
-                trace.count("device.h2d", 9)
-                w, q, h, ov = _JAX_REDUCE_BATCH(ok, key, pu_lo, pu_hi,
-                                                leafcnt, nchild, hopsum,
-                                                depth, lqc)
+                plan = _PLANS.get((pu_lo, pu_hi, leafcnt, nchild, hopsum,
+                                   depth), lqc)
+                trace.count("device.h2d")
+                out = _JAX_REDUCE_BATCH(_pack_scans(ok, key), plan)
             with trace.span("device.walk_reduce_batch.fetch"):
-                trace.count("device.fetch", 4)
-                return (np.asarray(w, dtype=np.int64),
-                        np.asarray(q, dtype=np.int64),
-                        np.asarray(h, dtype=np.int64),
-                        np.asarray(ov, dtype=np.float64))
+                trace.count("device.fetch")
+                a = np.asarray(out)
+                return (a[:, 0].astype(np.int64), a[:, 1].astype(np.int64),
+                        a[:, 2].astype(np.int64),
+                        a.view(np.float32)[:, 3].astype(np.float64))
     n = len(ok)
     winners = np.empty(n, dtype=np.int64)
     queries = np.empty(n, dtype=np.int64)

@@ -227,6 +227,34 @@ def test_slowdown_kernel_matches_numpy_oracle(n, r):
     np.testing.assert_allclose(pal, ref, rtol=1e-6, atol=0)   # fp32
 
 
+def test_slowdown_kernel_slices_pools_wider_than_its_widest_bucket(
+        monkeypatch):
+    """A pool wider than ``MAX_BUCKET`` goes through in slices of that
+    width: the same factors as one call, and no bucket above the cap."""
+    from repro.kernels import slowdown_kernel as sk
+    rng = np.random.default_rng(3)
+    n, r = 150, 6
+    x = rng.uniform(0.0, 3.0, (n, r)) * (rng.random((n, r)) > 0.4)
+    beta = rng.uniform(0.0, 0.5, r)
+    mem = rng.uniform(0.0, 1.0, n)
+    mt = rng.uniform(0.0, 1.0, n) * (rng.random(n) > 0.5)
+    whole = sk.slowdown_factors_pallas(x, beta, mem, mt, 0.12,
+                                       interpret=True)
+    widths = []
+    call = sk.factors_call
+
+    def recorded(xt, *a, **kw):
+        widths.append(xt.shape[1])
+        return call(xt, *a, **kw)
+
+    monkeypatch.setattr(sk, "MAX_BUCKET", 64)
+    monkeypatch.setattr(sk, "factors_call", recorded)
+    sliced = sk.slowdown_factors_pallas(x, beta, mem, mt, 0.12,
+                                        interpret=True)
+    assert widths == [64, 64, 32]
+    np.testing.assert_array_equal(sliced, whole)
+
+
 @pytest.mark.parametrize("jax_loaded", [True, False])
 def test_aggregate_selects_numpy_on_cpu(monkeypatch, jax_loaded):
     """Off-TPU the selector picks the float64 numpy path, whatever has

@@ -83,14 +83,9 @@ def test_segment_min_compiles(one_chip):
 
 
 def _reduce_specs(one_chip, n_pus, n_nodes, batch=()):
-    i32, f32 = jnp.int32, jnp.float32
-    pu = batch + (n_pus,)
-    node = batch + (n_nodes,)
-    return (_spec(one_chip, pu, jnp.bool_), _spec(one_chip, pu, f32),
-            _spec(one_chip, node, i32), _spec(one_chip, node, i32),
-            _spec(one_chip, node, i32), _spec(one_chip, node, i32),
-            _spec(one_chip, node, f32), _spec(one_chip, node, f32),
-            _spec(one_chip, (), f32))
+    # the packed operands: ok and keys in fp32, the plan constants in int32
+    return (_spec(one_chip, batch + (2, n_pus), jnp.float32),
+            _spec(one_chip, batch + (6 * n_nodes + 1,), jnp.int32))
 
 
 @pytest.mark.parametrize("n_pus,n_nodes", [_DEVICE_SCAN, _FLEET_SCAN])

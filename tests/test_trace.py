@@ -215,6 +215,7 @@ def test_device_entries_count_and_nest_on_the_jax_path(tmp_path,
     from repro.kernels.slowdown_kernel import slowdown_factors_pallas
     monkeypatch.delenv("REPRO_WALK_KERNEL", raising=False)
     monkeypatch.setattr(walk_kernel, "_AUTO_JAX", True)
+    monkeypatch.setattr(walk_kernel, "_PLANS", walk_kernel._ResidentPlans(8))
     n = 16
     ok = np.ones(n, dtype=bool)
     key = np.arange(n, dtype=np.float64)
@@ -233,8 +234,10 @@ def test_device_entries_count_and_nest_on_the_jax_path(tmp_path,
     s0 = trace.snapshot()
     ev = _profile(tmp_path, work)
     s1 = trace.snapshot()
-    assert _count(s0, s1, "device.fetch") == 4
-    assert _count(s0, s1, "device.h2d") == 9
+    # one packed read; one packed upload, the plan's constants resident
+    assert _count(s0, s1, "device.fetch") == 1
+    assert _count(s0, s1, "device.h2d") == 1
+    assert _count(s0, s1, "cache.plan_dev.hit") == 1
     for name in ("device.walk_reduce", "device.walk_reduce.call",
                  "device.walk_reduce.fetch"):
         assert _delta(s0, s1, name)[0] == 1
@@ -250,8 +253,10 @@ def test_device_entries_count_and_nest_on_the_jax_path(tmp_path,
                                   *(np.stack([c, c]) for c in cols), 0.5)
     slowdown_factors_pallas(x, *args)
     s1 = trace.snapshot()
-    assert _count(s0, s1, "device.fetch") == 4 + 1
-    assert _count(s0, s1, "device.h2d") == 9 + 4
+    # the two-row stack's constants are new: uploaded once, then resident
+    assert _count(s0, s1, "device.fetch") == 1 + 1
+    assert _count(s0, s1, "device.h2d") == 2 + 4
+    assert _count(s0, s1, "cache.plan_dev.miss") == 1
     for entry in ("device.walk_reduce_batch", "device.slowdown"):
         n_e, wall, own = _delta(s0, s1, entry)
         _, wc, _ = _delta(s0, s1, entry + ".call")
