@@ -38,6 +38,25 @@ def test_cell_runs_correct_at_small_fleet(name, cpu_devices):
     assert list(out)[-1] == "checks"
 
 
+def _later_cell_metrics(traced):
+    # a later cell brings its own workload entry and edits none that exists
+    spec = json.loads(json.dumps(SPEC))
+    spec["workloads"].append({"name": "later-x64.replay",
+                              "config": "mining-x64", "traffic": "replay",
+                              "chips": 1, "why": "a cell added later"})
+    return {m["name"] for m in cell_metrics(spec, "later-x64.replay",
+                                            traced=traced)}
+
+
+def test_cell_added_by_entries_alone_reports_end_to_end():
+    assert _later_cell_metrics(False) == {"decisions_per_s", "setup_s"}
+
+
+def test_cell_added_by_entries_alone_reports_every_per_layer_metric():
+    got = _later_cell_metrics(True)
+    assert got and got == {m["name"] for m in SPEC["per_layer"]}
+
+
 # mixes kept as data for later cells: each runs through the same generator
 KEPT = [("mining-x64", "paced"), ("wireless-x64", "replay"),
         ("mining-x64", "zipf.replay")]
