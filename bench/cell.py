@@ -1,9 +1,17 @@
 """One cell of the benchmark: the fleet, the traffic, the loop, the window.
 
-A cell is a configuration (``bench/configs/<name>.json``: fleet, sensors,
-readings, admission, churn) under a traffic mix
+A cell is a configuration (``bench/configs/<name>.json``: fleet, sources,
+the request, admission, churn) under a traffic mix
 (``bench/traffic/<name>.json``: mode, origins, rate).  ``BENCHMARK.json``
 names both; nothing here knows any cell by name.
+
+A request is a DAG of stages (:func:`request_stages`): a configuration
+gives them under ``request.stages`` (kind, predecessors, bytes in and out,
+pinned to the origin edge or not, a deadline per origin edge kind), or as
+a mining ``reading``, whose tasks are independent stages that share one
+deadline.  Its sources are sensors at ``reading.hz`` (one Poisson stream)
+or, under ``sources``, one periodic source on every edge at a rate per
+edge kind.  One request gets one final verdict: that is a decision.
 
 The loop under test is the program's ``ServeLoop`` driven through
 :class:`BenchLoop`, which overrides only the wave entry ``_admit_wave`` to
@@ -37,9 +45,10 @@ REPLAY_CEILING_PER_S = 2000
 
 @dataclass
 class ReadingRecord:
-    """One reading in one wave, as the program decided it: its tasks as
-    ``(uid, kind, chosen PU, predicted total, charged overhead)`` and its
-    verdict after the wave (``accepted``, ``deferred``, ``rejected``)."""
+    """One request in one wave, as the program decided it: its tasks, in
+    stage order, as ``(uid, kind, chosen PU, predicted total, charged
+    overhead)`` and its verdict after the wave (``accepted``,
+    ``deferred``, ``rejected``)."""
 
     rid: int
     origin: str
@@ -96,6 +105,48 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
 
 
 # ---------------------------------------------------------------------------
+# the request and its sources, as the configuration states them
+# ---------------------------------------------------------------------------
+def request_stages(conf: dict) -> list[dict]:
+    """The request's stages in order, each with ``kind``, ``preds``
+    (indices of earlier stages), ``input_bytes``, ``output_bytes``,
+    ``deadline_s`` (seconds, or seconds per origin edge kind) and, where
+    the configuration states it, ``pinned``."""
+    if "request" in conf:
+        return conf["request"]["stages"]
+    rd = conf["reading"]
+    return [{"kind": k, "preds": [], "input_bytes": rd["input_bytes"],
+             "output_bytes": rd["output_bytes"],
+             "deadline_s": rd["deadline_s"]} for k in rd["tasks"]]
+
+
+def stage_deadline(stage: dict, edge_kind: str) -> float:
+    dl = stage["deadline_s"]
+    return float(dl[edge_kind] if isinstance(dl, dict) else dl)
+
+
+def returns_to_pinned(stages: list[dict]) -> list[bool]:
+    """Per stage: whether a pinned stage consumes its output, which must
+    then travel back to the origin edge."""
+    out = [False] * len(stages)
+    for st in stages:
+        if st.get("pinned"):
+            for p in st["preds"]:
+                out[p] = True
+    return out
+
+
+def request_rate(conf: dict) -> float:
+    """Requests per simulated second that the configuration's sources
+    offer."""
+    src = conf.get("sources")
+    if src is None:
+        return conf["sensors"] * conf["reading"]["hz"]
+    return float(sum(n * src["fps"][kind]
+                     for kind, n in conf["fleet"]["edges"].items()))
+
+
+# ---------------------------------------------------------------------------
 # the stream: arrival instants and origins, all drawn from the seed
 # ---------------------------------------------------------------------------
 def seed_streams(seed: int, n: int = 4) -> list[int]:
@@ -108,10 +159,10 @@ def seed_streams(seed: int, n: int = 4) -> list[int]:
 
 @dataclass
 class Stream:
-    """The readings a run can draw on, in arrival order: simulated arrival
-    instants (a Poisson superposition of the sensors) and each reading's
-    origin edge.  ``times`` is the arrival-process surface ``ServeLoop``
-    reads."""
+    """The requests a run can draw on, in arrival order: simulated arrival
+    instants (a Poisson superposition of the sensors, or the merged
+    periodic sources) and each request's origin edge.  ``times`` is the
+    arrival-process surface ``ServeLoop`` reads."""
 
     arrivals: np.ndarray
     origins: list
@@ -127,40 +178,55 @@ class Stream:
 
 
 def readings_needed(cell: Cell, seconds: float) -> int:
-    """Readings the stream must hold so that no run of ``seconds`` can
+    """Requests the stream must hold so that no run of ``seconds`` can
     run dry: the warm-up, then the window at the traffic's ceiling rate."""
     tr = cell.traffic
     rate = (tr["decisions_per_s"] if tr["mode"] == "paced"
             else REPLAY_CEILING_PER_S)
-    sim_rate = cell.config["sensors"] * cell.config["reading"]["hz"]
+    sim_rate = request_rate(cell.config)
     warm = tr["warmup_sim_s"] * sim_rate
     return int(math.ceil(warm + 1.25 * rate * seconds + 64))
+
+
+def _sensor_origins(conf: dict, tr: dict, edges: list, n: int,
+                    s_org: int, s_rank: int) -> list:
+    """Each of ``n`` readings' origin edge, by the traffic's origin law."""
+    rng = np.random.default_rng(s_org)
+    if tr["origins"] == "sensors":
+        attach = yardstick.sensor_edges(edges, conf["sensors"],
+                                        conf["sensor_ring_weights"])
+        sensor = rng.integers(0, conf["sensors"], size=n)
+        return [attach[s] for s in sensor.tolist()]
+    if tr["origins"] == "zipf":
+        ranked = yardstick.zipf_ranked_edges(
+            edges, np.random.default_rng(s_rank))
+        ranks = yardstick.zipf_draw(len(ranked), tr["zipf_s"], n, rng)
+        return [ranked[r] for r in ranks.tolist()]
+    raise HarnessError(f"unknown origin law {tr['origins']!r}")
 
 
 def make_stream(cell: Cell, seed: int, seconds: float) -> Stream:
     conf, tr = cell.config, cell.traffic
     s_arr, s_org, s_rank, s_churn = seed_streams(seed)
-    sim_rate = conf["sensors"] * conf["reading"]["hz"]
+    sim_rate = request_rate(conf)
     n = readings_needed(cell, seconds)
     horizon = n / sim_rate
-    arrivals = yardstick.PoissonArrivals(sim_rate, seed=s_arr).times(horizon)
-    if len(arrivals) < 2:
-        raise HarnessError("the stream holds no readings")
     edges = yardstick.edge_names(conf["fleet"]["edges"])
-    rng = np.random.default_rng(s_org)
-    if tr["origins"] == "sensors":
-        attach = yardstick.sensor_edges(edges, conf["sensors"],
-                                        conf["sensor_ring_weights"])
-        sensor = rng.integers(0, conf["sensors"], size=len(arrivals))
-        origins = [attach[s] for s in sensor.tolist()]
-    elif tr["origins"] == "zipf":
-        ranked = yardstick.zipf_ranked_edges(
-            edges, np.random.default_rng(s_rank))
-        ranks = yardstick.zipf_draw(len(ranked), tr["zipf_s"],
-                                    len(arrivals), rng)
-        origins = [ranked[r] for r in ranks.tolist()]
+    if (tr["origins"] == "headsets") != ("sources" in conf):
+        raise HarnessError(f"origin law {tr['origins']!r} does not fit the "
+                           "configuration: 'headsets' takes its periodic "
+                           "sources, the other laws its sensors")
+    if tr["origins"] == "headsets":
+        src = conf["sources"]
+        arrivals, origins = yardstick.periodic_sources(
+            edges, src["fps"], horizon, np.random.default_rng(s_arr))
     else:
-        raise HarnessError(f"unknown origin law {tr['origins']!r}")
+        arrivals = yardstick.PoissonArrivals(sim_rate,
+                                             seed=s_arr).times(horizon)
+        origins = _sensor_origins(conf, tr, edges, len(arrivals), s_org,
+                                  s_rank)
+    if len(arrivals) < 2:
+        raise HarnessError("the stream holds no requests")
     churn = []
     ch = conf.get("churn")
     if ch:
@@ -297,11 +363,45 @@ def make_loop_class():
     return BenchLoop
 
 
+def request_builder(conf: dict, origins: list):
+    """``make_request(k, t)`` for the program's ``TenantSpec``: request
+    ``k`` as a ``TaskGraph`` of the configuration's stages, each released
+    at ``t`` from ``origins[k]`` with its deadline for that edge's kind, a
+    dependency on each predecessor, and the attributes the program reads
+    for a pinned stage (``pinned``) and for an output that returns to one
+    (``succ_pinned_bytes``), as ``core.workloads.vr_frame`` sets them."""
+    from repro.core import TaskGraph, make_task
+
+    stages = request_stages(conf)
+    back = returns_to_pinned(stages)
+    kind_of = dict(yardstick.edge_names(conf["fleet"]["edges"]))
+
+    def make_request(k: int, t: float):
+        g = TaskGraph(f"reading#{k}")
+        origin = origins[k]
+        tasks = []
+        for st, ret in zip(stages, back):
+            task = make_task(st["kind"], origin=origin,
+                             deadline=stage_deadline(st, kind_of[origin]),
+                             input_bytes=st["input_bytes"],
+                             output_bytes=st["output_bytes"],
+                             release_time=t)
+            if "pinned" in st:
+                task.attrs["pinned"] = bool(st["pinned"])
+            if ret:
+                task.attrs["succ_pinned_bytes"] = task.output_bytes
+            g.add(task, deps=[tasks[p] for p in st["preds"]])
+            tasks.append(task)
+        return g
+
+    return make_request
+
+
 def build_loop(cell: Cell, stream: Stream, plan: WindowPlan):
     """The configured fleet and its loop (not yet run)."""
-    from repro.core import (Churn, TaskGraph, TenantSpec, build_orchestrators,
+    from repro.core import (Churn, TenantSpec, build_orchestrators,
                             build_testbed, ground_truth_traverser,
-                            heye_traverser, make_task)
+                            heye_traverser)
     from repro.serve.admission import AdmissionController
 
     conf = cell.config
@@ -309,22 +409,10 @@ def build_loop(cell: Cell, stream: Stream, plan: WindowPlan):
     tb = build_testbed(edge_counts=dict(fl["edges"]),
                        server_counts=dict(fl["servers"]))
     root = build_orchestrators(tb.graph, heye_traverser(tb.graph))
-    rd = conf["reading"]
-    origins = stream.origins
-
-    def make_request(k: int, t: float) -> TaskGraph:
-        g = TaskGraph(f"reading#{k}")
-        for kind in rd["tasks"]:
-            g.add(make_task(kind, origin=origins[k],
-                            deadline=rd["deadline_s"],
-                            input_bytes=rd["input_bytes"],
-                            output_bytes=rd["output_bytes"],
-                            release_time=t))
-        return g
-
+    make_request = request_builder(conf, stream.origins)
     adm = conf["admission"]
     tenant = TenantSpec("readings", stream, make_request,
-                        sla=rd["deadline_s"])
+                        sla=conf.get("reading", {}).get("deadline_s"))
     loop = make_loop_class()(
         tb.graph, root, [tenant],
         truth=ground_truth_traverser(tb.graph, conf["truth_seed"]),

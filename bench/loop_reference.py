@@ -3,28 +3,39 @@
 Written from the paper's definitions, with the testbed as data
 (``bench/testbed.json``) and no import of the program.  It follows the
 program's run wave by wave, as a served model's reference follows the
-served tokens: each wave's instant, its readings, the PU the program chose
+served tokens: each wave's instant, its requests, the PU the program chose
 for each task and the program's verdict are taken as given, and everything
-else is computed here:
+else is computed here.  A request is a DAG of stages
+(``bench.cell.request_stages``); a wave walks its requests' stages level
+by level in dependency order, each level in task order, as the session's
+dependency frontier maps them:
 
 * the Alg. 1 walk of each task over the reference's own belief ledger:
   the origin device's PUs first, then the other devices of its cluster,
   then the other clusters, then the least-bad PU anywhere; at each level
   the feasible PU with the least predicted total (first in visit order on
-  ties).  A PU is feasible when it supports the task, its predicted total
-  (inbound transfer + queueing behind a full PU + standalone x slowdown)
-  meets the deadline, and no task already on its device would then miss
-  its own (Alg. 1 line 15);
+  ties).  A stage pinned to its origin may use that device's PUs alone.
+  A PU is feasible when it supports the task, its predicted total meets
+  the stage's deadline, and no task already on its device would then miss
+  its own (Alg. 1 line 15).  The predicted total is the inbound transfer
+  (the stage's input from its predecessors' devices, or from the origin
+  where it has none; the slowest leg), plus, off the origin, the return of
+  its output to the origin where a pinned stage consumes it, plus queueing
+  behind a full PU, plus standalone x slowdown;
 * the decoupled slowdown factor (section 3.4) of the task and of every
   task it joins;
 * the walk's scheduling overhead (Fig. 14), charged to the release time;
-* the admission verdict: accept unless a task's predicted total passes
-  deadline x slack, which defers the reading once and then rejects it;
+* the admission verdict, per request: accept unless a stage's predicted
+  total passes its deadline x slack, which defers the request once and
+  then rejects it;
 * the ground-truth timeline: a discrete-event simulation of the accepted
-  tasks' input transfers (links shared equally, then the route latency)
-  and compute (slowdown repriced whenever a device's set of running tasks
-  changes, per-task irregularity noise drawn at start, PUs queueing past
-  their tenancy), and bandwidth churn where the configuration has it.
+  tasks' transfers (a root stage's input from the origin, each other
+  stage's inputs as its predecessors' outputs from their devices; links
+  shared equally, then the route latency) and compute (a stage starts once
+  it is released and every input has landed; slowdown repriced whenever a
+  device's set of running tasks changes, per-task irregularity noise drawn
+  at start, PUs queueing past their tenancy), and bandwidth churn where
+  the configuration has it.
 
 :func:`compare` returns the compared numbers (``bench/check.py``).
 """
@@ -39,6 +50,8 @@ from pathlib import Path
 from typing import Optional
 
 import numpy as np
+
+from .cell import request_stages, returns_to_pinned, stage_deadline
 
 TESTBED = Path(__file__).resolve().parent / "testbed.json"
 
@@ -250,6 +263,14 @@ class TaskIn:
     mem: float
     dl: float
     in_bytes: float
+    srcs: tuple = ()               # devices its input comes from
+    ret_bytes: float = 0.0         # output owed back to the origin
+    pinned: bool = False
+
+    def __post_init__(self) -> None:
+        # a stage with no placed predecessor takes its input at the origin
+        if not self.srcs:
+            self.srcs = (self.origin,)
 
 
 @dataclass
@@ -277,13 +298,27 @@ class Walker:
                 self.belief[d] = [b for b in rows if b.est > now]
 
     # -- scoring -----------------------------------------------------------
+    def comm(self, t: TaskIn, d: int) -> float:
+        """Inbound transfer onto device ``d`` (the slowest of the legs
+        from ``t``'s sources) and, off the origin, the return leg."""
+        fl = self.fleet
+        c = 0.0
+        if t.in_bytes > 0:
+            for src in t.srcs:
+                if src != d:
+                    c = max(c, fl.transfer_time(src, d, t.in_bytes))
+        if t.ret_bytes > 0 and d != t.origin:
+            c += fl.transfer_time(d, t.origin, t.ret_bytes)
+        return c
+
     def score_device(self, t: TaskIn, d: int, now: float,
                      constrained: bool) -> list[Score]:
+        if t.pinned and d != t.origin:
+            return []
         fl = self.fleet
         acts = self.belief[d]
         cols = [(a.pu, a.u, a.m) for a in acts]
-        comm = fl.transfer_time(t.origin, d, t.in_bytes) \
-            if d != t.origin else 0.0
+        comm = self.comm(t, d)
         out = []
         for gid in fl.devices[d].pus:
             pu = fl.pus[gid]
@@ -322,10 +357,9 @@ class Walker:
         return True
 
     def _lower_bound(self, t: TaskIn, d: int) -> float:
-        fl = self.fleet
-        comm = fl.transfer_time(t.origin, d, t.in_bytes) \
-            if d != t.origin else 0.0
-        return comm + fl.least_standalone(t.kind, d)
+        if t.pinned and d != t.origin:
+            return math.inf
+        return self.comm(t, d) + self.fleet.least_standalone(t.kind, d)
 
     def _scan(self, t: TaskIn, devs: list, now: float, constrained: bool,
               want: int, every: bool = False):
@@ -422,6 +456,10 @@ class Job:
     release: float
     origin: int
     in_bytes: float
+    out_bytes: float = 0.0
+    n_preds: int = 0
+    succs: list = field(default_factory=list)    # consumer jobs
+    waiting: int = 1               # release + inputs still to land
     W: float = 0.0
     rate: float = 1.0
     t_last: float = 0.0
@@ -477,6 +515,7 @@ class Timeline:
     def inject(self, jobs: list) -> None:
         for j in jobs:
             self.jobs[j.uid] = j
+            j.waiting = j.n_preds + 1
         for j in jobs:
             self._push(j.release, _RELEASE, j.uid)
 
@@ -500,6 +539,11 @@ class Timeline:
         self.pool.setdefault(j.pu.dev, set()).add(j.uid)
         self.dirty_devs.add(j.pu.dev)
 
+    def _arrived(self, j: Job) -> None:
+        j.waiting -= 1
+        if j.waiting == 0:
+            self._start(j)
+
     def _finish(self, j: Job) -> None:
         j.eta = math.inf
         p = j.pu.gid
@@ -508,19 +552,24 @@ class Timeline:
         del self.running[j.uid]
         self.pool[j.pu.dev].discard(j.uid)
         self.done_log.append(j.uid)
+        for c in j.succs:
+            if not self._launch(c, j.pu.dev, j.out_bytes):
+                self._arrived(c)
         q = self.queue.get(p)
         if q:
             self._start(q.popleft())
         self.dirty_devs.add(j.pu.dev)
 
-    def _launch(self, j: Job) -> bool:
-        if j.origin == j.pu.dev or j.in_bytes <= 0:
+    def _launch(self, j: Job, src: int, nbytes: float) -> bool:
+        """Start the transfer of ``nbytes`` from device ``src`` onto
+        ``j``'s; False where nothing travels."""
+        if src == j.pu.dev or nbytes <= 0:
             return False
-        links = self.fleet.route(j.origin, j.pu.dev)
+        links = self.fleet.route(src, j.pu.dev)
         lat = 0.0
         for ln in links:
             lat += self.fleet.lat[ln]
-        x = Xfer(k=self.n_x, uid=j.uid, links=links, lat=lat, W=j.in_bytes,
+        x = Xfer(k=self.n_x, uid=j.uid, links=links, lat=lat, W=nbytes,
                  t_last=self.time)
         self.n_x += 1
         self.xlive[x.k] = x
@@ -609,10 +658,11 @@ class Timeline:
                     _, _, kind, payload = heapq.heappop(self.heap)
                     if kind == _RELEASE:
                         j = self.jobs[payload]
-                        if not self._launch(j):
-                            self._start(j)
+                        if j.n_preds or not self._launch(j, j.origin,
+                                                         j.in_bytes):
+                            self._arrived(j)
                     elif kind == _ARRIVE:
-                        self._start(self.jobs[payload])
+                        self._arrived(self.jobs[payload])
                     else:
                         self._intervene(payload)
                 jobs, xs = self._due(t)
@@ -640,7 +690,7 @@ class Timeline:
                     if x.lat > 0:
                         self._push(t + x.lat, _ARRIVE, x.uid)
                     else:
-                        self._start(self.jobs[x.uid])
+                        self._arrived(self.jobs[x.uid])
                 if not self._flush():
                     break
                 jobs, xs = self._due(t)
@@ -666,6 +716,16 @@ def load_testbed() -> dict:
         return json.load(f)
 
 
+def stage_levels(stages: list[dict]) -> list[list[int]]:
+    """Stage indices grouped by dependency depth: a stage's level is one
+    past its deepest predecessor's."""
+    depth: list[int] = []
+    for st in stages:
+        depth.append(1 + max((depth[p] for p in st["preds"]), default=-1))
+    return [[i for i, d in enumerate(depth) if d == lv]
+            for lv in range(max(depth) + 1)]
+
+
 def compare(conf: dict, churn: list, trail: list, stop_at: float,
             finish_of) -> dict:
     """Follow the program's ``trail`` (see ``bench.cell.WaveRecord``) and
@@ -674,7 +734,7 @@ def compare(conf: dict, churn: list, trail: list, stop_at: float,
     reference's least at the level the reference chose; inf where that PU
     lies outside the level or is infeasible there), ``predict_rel`` (the
     program's predicted total against the reference's, for the same PU),
-    ``overhead_rel`` (the walk's charged overhead), ``verdicts`` (readings
+    ``overhead_rel`` (the walk's charged overhead), ``verdicts`` (requests
     whose verdict differs), ``finish_rel`` (each task's finish time on the
     ground-truth timeline, up to the instant ``stop_at`` the program's run
     stopped at; ``finish_of(uid)`` is the program's, nan while
@@ -682,8 +742,11 @@ def compare(conf: dict, churn: list, trail: list, stop_at: float,
     tb = load_testbed()
     fleet = Fleet(conf, tb)
     walker = Walker(fleet)
-    rd = conf["reading"]
+    stages = request_stages(conf)
+    levels = stage_levels(stages)
+    back = returns_to_pinned(stages)
     adm = conf["admission"]
+    slack = float(adm["slack"])
     tl = Timeline(fleet, float(tb["slowdown"]["truth_noise"]),
                   int(conf["truth_seed"]),
                   [(t, tuple(e)) for t, e in churn])
@@ -691,44 +754,55 @@ def compare(conf: dict, churn: list, trail: list, stop_at: float,
     gap = p_rel = o_rel = f_rel = 0.0
     verdicts = 0
     injected: list[Job] = []
-    dl = float(rd["deadline_s"])
-    limit = dl * float(adm["slack"])
     for w in trail:
         now = w.now
         tl.advance(float(np.nextafter(now, -np.inf)))
         walker.drop(set(tl.drain()))
         walker.prune(now)
-        wave: list = []
-        for r in w.readings:
-            mine = []
-            for uid, kind, pu_name, total, overhead in r.tasks:
-                t = TaskIn(uid=uid, kind=kind,
-                           origin=fleet.device_of(r.origin),
+        # per request: (task, score, overhead, belief) of each stage
+        wave = [(r, [None] * len(r.tasks)) for r in w.readings]
+        for level in levels:
+            todo = sorted(((r.tasks[i][0], i, r, mine)
+                           for r, mine in wave for i in level),
+                          key=lambda x: x[0])
+            for uid, i, r, mine in todo:
+                _, kind, pu_name, total, overhead = r.tasks[i]
+                st = stages[i]
+                origin = fleet.device_of(r.origin)
+                pred_pus = [fleet.by_name.get(r.tasks[p][2], -1)
+                            for p in st["preds"] if r.tasks[p][2] is not None]
+                if -1 in pred_pus:     # a predecessor on a PU the testbed lacks
+                    gap = math.inf
+                srcs = {fleet.pus[g].dev for g in pred_pus if g >= 0}
+                t = TaskIn(uid=uid, kind=kind, origin=origin,
                            u=float(usage[kind]["pu"]),
-                           mem=float(usage[kind]["mem"]), dl=dl,
-                           in_bytes=float(rd["input_bytes"]))
+                           mem=float(usage[kind]["mem"]),
+                           dl=stage_deadline(st, fleet.devices[origin].kind),
+                           in_bytes=float(st["input_bytes"]),
+                           srcs=tuple(sorted(srcs)),
+                           ret_bytes=(float(st["output_bytes"]) if back[i]
+                                      else 0.0),
+                           pinned=bool(st.get("pinned")))
                 want = fleet.by_name.get(pu_name, -1) \
                     if pu_name is not None else -1
                 best, s, ov, in_scope = walker.walk(t, now, want)
                 if s is None or best is None:
                     gap = math.inf
-                    mine.append((t, None, 0.0, None))
+                    mine[i] = (t, None, 0.0, None)
                     continue
                 if s.pu.gid != best.pu.gid:
                     gap = max(gap, (s.total - best.total) / best.total
                               if in_scope else math.inf)
                 p_rel = max(p_rel, _rel(total, s.total))
                 o_rel = max(o_rel, _rel(overhead, ov))
-                b = walker.commit(t, s, now)
-                mine.append((t, s, ov, b))
-            wave.append((r, mine))
-        # overhead is charged once the wave is mapped
+                mine[i] = (t, s, ov, walker.commit(t, s, now))
+            # a level's overhead is charged once it is mapped, before the
+            # next level's walk reads the ledger
+            for _, i, _, mine in todo:
+                if mine[i][1] is not None:
+                    mine[i][3].rel = now + mine[i][2]
         for r, mine in wave:
-            for item in mine:
-                if item[1] is not None:
-                    item[3].rel = now + item[2]
-        for r, mine in wave:
-            late = any(item[1] is None or item[1].total > limit
+            late = any(item[1] is None or item[1].total > item[0].dl * slack
                        for item in mine)
             if not late:
                 want = "accepted"
@@ -740,17 +814,23 @@ def compare(conf: dict, churn: list, trail: list, stop_at: float,
             if r.verdict != "accepted":
                 walker.drop({item[0].uid for item in mine})
                 continue
-            jobs = []
-            for item in mine:
-                t, s = item[0], item[1]
+            jobs: list = [None] * len(mine)
+            for i, (t, s, ov, _) in enumerate(mine):
                 if s is None:
                     continue
-                jobs.append(Job(uid=t.uid, pu=s.pu,
-                                sa=fleet.standalone(t.kind, s.pu), u=t.u,
-                                m=min(t.mem, s.pu.mem_cap),
-                                irr=float(irr.get(t.kind, 1.0)),
-                                release=now + item[2], origin=t.origin,
-                                in_bytes=t.in_bytes))
+                st = stages[i]
+                jobs[i] = Job(uid=t.uid, pu=s.pu,
+                              sa=fleet.standalone(t.kind, s.pu), u=t.u,
+                              m=min(t.mem, s.pu.mem_cap),
+                              irr=float(irr.get(t.kind, 1.0)),
+                              release=now + ov, origin=t.origin,
+                              in_bytes=t.in_bytes,
+                              out_bytes=float(st["output_bytes"]),
+                              n_preds=len(st["preds"]))
+                for p in st["preds"]:
+                    if jobs[p] is not None:
+                        jobs[p].succs.append(jobs[i])
+            jobs = [j for j in jobs if j is not None]
             tl.inject(jobs)
             injected.extend(jobs)
     tl.advance(float(np.nextafter(stop_at, -np.inf)))

@@ -7,11 +7,14 @@ Each function is a copy of the program's generator of the same job
 ``wireless_churn_schedule`` in ``core/workloads.py``); the tests hold each
 copy to the original at a small fleet.  Sensors are spread along the ring
 (``sensor_edges``) where the program fills it in order, and the Zipf
-ranking is the benchmark's own.  Nothing here imports the program.
+ranking and the phased periodic sources (``periodic_sources``, where the
+program's ``vr_workload`` starts every headset at 0) are the benchmark's
+own.  Nothing here imports the program.
 """
 from __future__ import annotations
 
 import itertools
+import math
 import random
 
 import numpy as np
@@ -81,6 +84,29 @@ def sensor_edges(edges: list[tuple[str, str]], n_sensors: int,
     connected to the edges by their computing capability)."""
     ring = capability_ring(edges, weights)
     return [ring[(s * len(ring)) // n_sensors] for s in range(n_sensors)]
+
+
+def periodic_sources(edges: list[tuple[str, str]], fps: dict,
+                     horizon: float, rng: np.random.Generator
+                     ) -> tuple[np.ndarray, list[str]]:
+    """Arrival instants in ``[0, horizon)`` and origin edges of one
+    periodic source on every edge, in time order: each source (a headset)
+    sends one request per period ``1 / fps[kind]`` from a phase drawn
+    uniformly over its first period, sources taken in fleet order.  Every
+    seed gives each source the same rate."""
+    times, owner = [], []
+    for e, (_, kind) in enumerate(edges):
+        period = 1.0 / fps[kind]
+        phase = rng.uniform(0.0, period)
+        t = phase + period * np.arange(
+            max(0, math.ceil((horizon - phase) / period)))
+        t = t[t < horizon]
+        times.append(t)
+        owner.append(np.full(len(t), e))
+    t = np.concatenate(times)
+    order = np.argsort(t, kind="stable")
+    names = [name for name, _ in edges]
+    return t[order], [names[e] for e in np.concatenate(owner)[order].tolist()]
 
 
 def zipf_ranked_edges(edges: list[tuple[str, str]],
